@@ -20,6 +20,9 @@ costs one compile set; its regeneration is the engine-counter delta.
     PYTHONPATH=src:. python benchmarks/serve_bench.py           # full
     PYTHONPATH=src:. python benchmarks/serve_bench.py --smoke   # CI gate
 
+``--smoke`` always runs on the CPU (``jax_platforms=cpu``), also on a
+machine with a chip: it is a determinism gate over CPU fingerprints.
+
 The smoke gate additionally asserts:
 
   * **chaos mode** — the same SAGA run under a ``cluster.faults``
@@ -69,6 +72,7 @@ from repro.cluster.faults import chaos_plan
 from repro.cluster.workload import runtime_requests
 from repro.configs import get_config, load_all
 from repro.core.coordinator import SAGAConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.serving.disagg import ROLE_DECODE, ROLE_PREFILL
 from repro.serving.runtime import (AgentRequest, RuntimePerf,
@@ -560,6 +564,7 @@ def smoke() -> None:
     for hashseed in ("0", "424242"):
         env = dict(os.environ)
         env["PYTHONHASHSEED"] = hashseed
+        env["JAX_PLATFORMS"] = "cpu"
         r = subprocess.run([sys.executable, __file__, "--smoke-emit"],
                            env=env, capture_output=True, text=True,
                            timeout=240)
@@ -599,6 +604,12 @@ def main() -> None:
     ap.add_argument("--smoke-emit", action="store_true",
                     help="internal: print the determinism fingerprint")
     args = ap.parse_args()
+    if args.smoke or args.smoke_emit:
+        # the smoke gate runs on the CPU wherever it is started: its
+        # committed fingerprints are CPU numerics, and its cross-
+        # PYTHONHASHSEED children could not reach a chip this process holds
+        jax.config.update("jax_platforms", "cpu")
+    enable_compile_cache()
     if args.smoke_emit:
         print(_fingerprint())
         print(_disagg_fingerprint())

@@ -43,6 +43,7 @@ import numpy as np
 
 from repro.configs import get_config, load_all
 from repro.core.coordinator import SAGAConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.serving.client import SagaClient
 from repro.serving.frontend import AsyncServingDriver, SagaHTTPProxy
@@ -190,6 +191,7 @@ def main() -> None:
                     choices=("saga-affinity", "round-robin",
                              "least-loaded"))
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         args.sessions, args.spread_s = 200, 4.0
     out = asyncio.run(_soak(args.sessions, args.spread_s,

@@ -11,6 +11,9 @@ BlockSpec VMEM tiling), ops.py (jit'd wrapper), ref.py (pure-jnp oracle):
   rwkv6/            WKV6 data-dependent-decay recurrence (chunked)
   mamba_scan/       selective-SSM scan (chunked)
 
-All are validated in interpret=True mode on CPU against ref.py across
-shape/dtype sweeps (tests/test_kernels.py).
+Every op compiles for the TPU by default; ``interpret=True`` runs the
+kernel body on any backend.  All are validated in interpret mode on the CPU
+against ref.py across shape/dtype sweeps (tests/test_kernels.py), and the
+paged decode kernel is compiled ahead of time for a v5e chip
+(tests/test_chip_compile.py).
 """

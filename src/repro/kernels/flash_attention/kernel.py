@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -72,7 +71,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
                          block_q: int = 128, block_k: int = 128,
-                         interpret: bool = True):
+                         interpret: bool = False):
     """q,k,v: (BH, S, D) with identical head counts (GQA pre-expanded).
     Returns (BH, Sq, D)."""
     BH, Sq, D = q.shape
@@ -100,7 +99,7 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -2,7 +2,7 @@
 
 Accepts model-layout tensors (B, S, H|K, D), expands GQA, folds heads
 into the batch grid dimension, and dispatches to the Pallas kernel
-(interpret=True on CPU; compiled on TPU).
+(compiled for the TPU; ``interpret=True`` runs the body on any backend).
 """
 from __future__ import annotations
 
@@ -15,18 +15,12 @@ from repro.kernels.flash_attention.kernel import flash_attention_bhsd
 from repro.models.layers import expand_kv
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
                                              "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool | None = None):
+                    interpret: bool = False):
     """q: (B, Sq, H, D); k/v: (B, Sk, K, D).  Returns (B, Sq, H, D)."""
-    if interpret is None:
-        interpret = not _on_tpu()
     B, Sq, H, D = q.shape
     k = expand_kv(k, H)
     v = expand_kv(v, H)

@@ -14,8 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
-
 
 def _mamba_kernel(x_ref, dt_ref, b_ref, c_ref, alog_ref, d_ref, o_ref,
                   h_ref, *, chunk: int, bd: int, ds: int):
@@ -43,7 +41,7 @@ def _mamba_kernel(x_ref, dt_ref, b_ref, c_ref, alog_ref, d_ref, o_ref,
 
 
 def mamba_scan_btd(x, dt, Bc, Cc, A_log, D, *, block_d: int = 256,
-                   chunk: int = 64, interpret: bool = True):
+                   chunk: int = 64, interpret: bool = False):
     """x, dt: (B, T, di); Bc, Cc: (B, T, ds); A_log: (di, ds); D: (di,).
     Returns y: (B, T, di) f32 (without gating)."""
     B, T, di = x.shape
@@ -67,7 +65,7 @@ def mamba_scan_btd(x, dt, Bc, Cc, A_log, D, *, block_d: int = 256,
         out_specs=pl.BlockSpec((1, c, bd), lambda b, d, j: (b, j, d)),
         out_shape=jax.ShapeDtypeStruct((B, T, di), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bd, ds), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, Bc, Cc, A_log, D)

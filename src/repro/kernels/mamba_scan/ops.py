@@ -11,9 +11,7 @@ from repro.kernels.mamba_scan.kernel import mamba_scan_btd
 @functools.partial(jax.jit, static_argnames=("block_d", "chunk",
                                              "interpret"))
 def mamba_scan(x, dt, Bc, Cc, A_log, D, *, block_d: int = 256,
-               chunk: int = 64, interpret: bool | None = None):
+               chunk: int = 64, interpret: bool = False):
     """x, dt: (B, T, di); Bc, Cc: (B, T, ds); A_log: (di, ds); D: (di,)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return mamba_scan_btd(x, dt, Bc, Cc, A_log, D, block_d=block_d,
                           chunk=chunk, interpret=interpret)

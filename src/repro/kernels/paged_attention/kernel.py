@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -75,7 +74,7 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lens, *,
-                           interpret: bool = True):
+                           interpret: bool = False):
     """q: (B, H, dh); pools: (num_blocks, block, K, dh);
     block_tables: (B, nb) int32; lens: (B,) int32 -> (B, H, dh)."""
     B, H, dh = q.shape
@@ -107,7 +106,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lens, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, dh), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables, lens, q, k_pool, v_pool)

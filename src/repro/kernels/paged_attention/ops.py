@@ -1,11 +1,11 @@
 """Jit'd public wrappers for paged decode attention.
 
-``paged_attention`` is the single-layer kernel entry (Pallas on TPU,
-interpret mode elsewhere).  ``paged_decode_step`` is the batched
-multi-layer entry the serving layout uses: it dynamic-updates the new
-step's K/V into each session's current tail block of the
-(L, num_blocks, block, K, dh) pool arrays, then attends every layer
-over the block tables — append + attend in one jitted call, no
+``paged_attention`` is the single-layer kernel entry (compiled for the
+TPU; ``interpret=True`` runs it on any backend).  ``paged_decode_step``
+is the batched multi-layer entry the serving layout uses: it
+dynamic-updates the new step's K/V into each session's current tail
+block of the (L, num_blocks, block, K, dh) pool arrays, then attends
+every layer over the block tables — append + attend in one jitted call, no
 contiguous copy of parked KV anywhere.
 """
 from __future__ import annotations
@@ -20,11 +20,9 @@ from repro.kernels.paged_attention.kernel import paged_decode_attention
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention(q, k_pool, v_pool, block_tables, lens,
-                    interpret: bool | None = None):
+                    interpret: bool = False):
     """q: (B, H, dh); pools: (num_blocks, block, K, dh);
     block_tables: (B, nb) int32; lens: (B,) int32."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return paged_decode_attention(q, k_pool, v_pool, block_tables, lens,
                                   interpret=interpret)
 
@@ -32,7 +30,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, lens,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_step(q, k_new, v_new, k_pool, v_pool, block_tables,
                       lens, append_blocks, append_offsets,
-                      interpret: bool | None = None):
+                      interpret: bool = False):
     """Batched multi-layer paged decode: append the step's K/V, then
     attend over block tables, for all L layers in one call.
 
@@ -47,8 +45,6 @@ def paged_decode_step(q, k_new, v_new, k_pool, v_pool, block_tables,
     Returns (out (L, B, H, dh), k_pool, v_pool) with the pools updated
     in place of the tail blocks only — parked KV never moves.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     kp = k_pool.at[:, append_blocks, append_offsets].set(
         k_new.astype(k_pool.dtype), mode="drop")
     vp = v_pool.at[:, append_blocks, append_offsets].set(

@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
-
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_ref, *,
                  chunk: int, dh: int):
@@ -44,7 +42,7 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_ref, *,
     state_ref[...] = state
 
 
-def wkv6_bht(r, k, v, w, u, *, chunk: int = 64, interpret: bool = True):
+def wkv6_bht(r, k, v, w, u, *, chunk: int = 64, interpret: bool = False):
     """r,k,v,w: (BH, T, dh); u: (BH, dh).  Returns (BH, T, dh) f32."""
     BH, T, dh = r.shape
     c = min(chunk, T)
@@ -65,7 +63,7 @@ def wkv6_bht(r, k, v, w, u, *, chunk: int = 64, interpret: bool = True):
         out_specs=pl.BlockSpec((1, c, dh), lambda b, j: (b, j, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, dh), jnp.float32),
         scratch_shapes=[pltpu.VMEM((dh, dh), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, w, u)

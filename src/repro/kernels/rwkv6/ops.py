@@ -10,10 +10,8 @@ from repro.kernels.rwkv6.kernel import wkv6_bht
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def wkv6(r, k, v, w, u, *, chunk: int = 64, interpret: bool | None = None):
+def wkv6(r, k, v, w, u, *, chunk: int = 64, interpret: bool = False):
     """r,k,v,w: (B, T, H, dh); u: (H, dh) -> (B, T, H, dh) f32."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     B, T, H, dh = r.shape
 
     def fold(x):

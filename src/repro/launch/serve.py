@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.configs import get_config, load_all
 from repro.core.coordinator import SAGAConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.serving.server import AgentRequest, MultiWorkerServer
 
@@ -36,6 +37,7 @@ def main():
                     help="request-level scheduling instead of SAGA")
     args = ap.parse_args()
 
+    enable_compile_cache()
     load_all()
     cfg = get_config(args.arch)
     params = lm.init_params(cfg, jax.random.PRNGKey(0))

@@ -593,7 +593,6 @@ def rs_out_proj(y, w, env: ShardingEnv, einsum_str: str):
     sequence-parallel layout directly (half the bytes of the all-reduce
     XLA otherwise emits).  Used when opts['rs_matmul'] is set and the
     contraction dims are 'model'-sharded."""
-    from jax.experimental.shard_map import shard_map
     bt = env.batch_axes
     S = y.shape[1]
     if (env.tp <= 1 or S % env.tp != 0
@@ -613,8 +612,8 @@ def rs_out_proj(y, w, env: ShardingEnv, einsum_str: str):
         return lax.psum_scatter(part, "model", scatter_dimension=1,
                                 tiled=True)
 
-    fn = shard_map(body, mesh=env.mesh, in_specs=(y_spec, w_spec),
-                   out_specs=out_spec, check_rep=False)
+    fn = jax.shard_map(body, mesh=env.mesh, in_specs=(y_spec, w_spec),
+                       out_specs=out_spec, check_vma=False)
     return fn(y, w)
 
 
@@ -792,10 +791,10 @@ def moe_ep(x, p, cfg, env: ShardingEnv, capacity_factor: float = 1.25):
         y2 = lax.psum(y2, tp_ax)
         return y2.reshape(xb.shape)
 
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(body_fullshard if fullshard else body, mesh=env.mesh,
-                   in_specs=(x_spec, r_spec, w1_spec, w1_spec, w2_spec),
-                   out_specs=out_spec, check_rep=False)
+    fn = jax.shard_map(body_fullshard if fullshard else body,
+                       mesh=env.mesh,
+                       in_specs=(x_spec, r_spec, w1_spec, w1_spec, w2_spec),
+                       out_specs=out_spec, check_vma=False)
     return fn(x, p["router"], p["w1"], p["w3"], p["w2"])
 
 

@@ -24,7 +24,9 @@ Architecture map (module -> paper section):
     the original contiguous-slot gather path as the reference oracle —
     both modes emit bit-identical token ids.  Admission is
     non-asserting: a full engine returns ``None`` and the runtime
-    queues.
+    queues.  Each engine owns one device (the runtime places engine
+    ``w`` on ``jax.devices()[w % n]``): params replica, pool and inputs
+    live there, and imported KV is moved onto it first.
   * ``events`` — deterministic virtual-time event heap + AFS-ordered
     ``SessionQueue`` (§6 admission); the byte-identical replay
     substrate.

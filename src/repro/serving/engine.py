@@ -87,6 +87,12 @@ def _jitted_fns(cfg: ModelConfig, env: ShardingEnv):
     return fns
 
 
+def serving_env() -> ShardingEnv:
+    """The single-device sharding environment engines serve under."""
+    return ShardingEnv(None, opts={"remat": False, "sp": False,
+                                   "moe_impl": "dense"})
+
+
 @dataclasses.dataclass
 class SlotState:
     session_id: Optional[str] = None
@@ -99,16 +105,18 @@ class Engine:
     def __init__(self, cfg: ModelConfig, params, *, n_slots: int = 4,
                  max_len: int = 512, pool_blocks: int = 64,
                  block_size: int = 16, env: Optional[ShardingEnv] = None,
-                 paged: bool = True):
+                 paged: bool = True, device: Optional[jax.Device] = None):
         assert not cfg.enc_dec and cfg.family in ("dense", "moe", "vlm"), \
             "engine demo supports decoder-only KV families"
         assert not cfg.use_mla, \
             "engine KV paths assume the GQA (k, v) cache layout"
         self.cfg = cfg
-        self.params = params
-        self.env = env or ShardingEnv(None, opts={"remat": False,
-                                                  "sp": False,
-                                                  "moe_impl": "dense"})
+        # the engine owns one device: its params replica, its pool and
+        # every input it feeds the jitted steps live there (a no-op copy
+        # when ``params`` already sit on it)
+        self.device = device if device is not None else jax.devices()[0]
+        self.params = jax.device_put(params, self.device)
+        self.env = env or serving_env()
         self.n_slots = n_slots
         self.max_len = max_len
         self.paged = paged
@@ -125,10 +133,12 @@ class Engine:
         else:
             self.max_nb = 0
             headroom = 0
-            self.cache = lm.init_cache(cfg, n_slots, max_len)
+            self.cache = jax.device_put(lm.init_cache(cfg, n_slots, max_len),
+                                        self.device)
         self.pool = PagedKVPool(cfg.n_layers, pool_blocks, block_size,
                                 cfg.n_kv_heads, cfg.head_dim,
-                                headroom_blocks=headroom)
+                                headroom_blocks=headroom,
+                                device=self.device)
         # prefill compile quantum: a whole number of blocks AND of the
         # base bucket, so a bucket boundary never splits a tail block
         self._prefill_quantum = (_PREFILL_BUCKET * block_size
@@ -148,6 +158,9 @@ class Engine:
 
         (self._jit_decode, self._jit_prefill,
          self._jit_paged_decode) = _jitted_fns(self.cfg, self.env)
+
+    def _put(self, x) -> jnp.ndarray:
+        return jax.device_put(x, self.device)
 
     # -- slot management -----------------------------------------------------
     def free_slot(self) -> Optional[int]:
@@ -186,7 +199,7 @@ class Engine:
         pad_to = max(pad_to, n)
         padded = np.zeros(pad_to, np.int32)
         padded[:n] = tokens
-        _, cache = self._jit_prefill(self.params, jnp.asarray(padded[None]),
+        _, cache = self._jit_prefill(self.params, self._put(padded[None]),
                                      pad_to=pad_to)
         return cache["k"][:, 0, :n], cache["v"][:, 0, :n]
 
@@ -270,8 +283,7 @@ class Engine:
                 tok[s, 0] = t
                 pos[s] = self.slots[s].length
             logits, self.cache = self._jit_decode(
-                self.params, jnp.asarray(tok), self.cache,
-                jnp.asarray(pos))
+                self.params, self._put(tok), self.cache, self._put(pos))
             nxt = np.asarray(jnp.argmax(logits[:, 0, :], axis=-1))
             for s in cur:
                 self.slots[s].length += 1
@@ -288,34 +300,41 @@ class Engine:
         write nowhere."""
         out: Dict[int, List[int]] = {s: [] for s in slot_tokens}
         cur = dict(slot_tokens)
-        pool = self.pool
-        sentinel = pool.total_blocks
         for _ in range(n_steps):
-            tok = np.zeros((self.n_slots, 1), np.int32)
-            pos = np.zeros((self.n_slots,), np.int32)
-            tables = np.zeros((self.n_slots, self.max_nb), np.int32)
-            ablk = np.full((self.n_slots,), sentinel, np.int32)
-            aoff = np.zeros((self.n_slots,), np.int32)
-            for s, t in cur.items():
-                sid = self.slots[s].session_id
-                pool.ensure_tail_room(sid)
-                tok[s, 0] = t
-                pos[s] = self.slots[s].length
-                tbl = pool.tables[sid]
-                tables[s, :len(tbl)] = tbl
-                ablk[s], aoff[s] = pool.tail_slot(sid)
-            logits, pool.k_pool, pool.v_pool = self._jit_paged_decode(
-                self.params, jnp.asarray(tok), pool.k_pool, pool.v_pool,
-                jnp.asarray(tables), jnp.asarray(pos),
-                jnp.asarray(ablk), jnp.asarray(aoff))
+            logits = self.paged_step_logits(cur)
             nxt = np.asarray(jnp.argmax(logits[:, 0, :], axis=-1))
             for s in cur:
-                pool.append_token(self.slots[s].session_id)
-                self.slots[s].length += 1
                 out[s].append(int(nxt[s]))
                 cur[s] = int(nxt[s])
-            self.decode_steps += 1
         return out
+
+    def paged_step_logits(self, slot_tokens: Dict[int, int]) -> jnp.ndarray:
+        """One batched paged decode step: feed ``{slot: token id}``,
+        append each row's K/V into its tail block, and return the
+        (n_slots, 1, vocab) logits (idle rows are garbage)."""
+        pool = self.pool
+        tok = np.zeros((self.n_slots, 1), np.int32)
+        pos = np.zeros((self.n_slots,), np.int32)
+        tables = np.zeros((self.n_slots, self.max_nb), np.int32)
+        ablk = np.full((self.n_slots,), pool.total_blocks, np.int32)
+        aoff = np.zeros((self.n_slots,), np.int32)
+        for s, t in slot_tokens.items():
+            sid = self.slots[s].session_id
+            pool.ensure_tail_room(sid)
+            tok[s, 0] = t
+            pos[s] = self.slots[s].length
+            tbl = pool.tables[sid]
+            tables[s, :len(tbl)] = tbl
+            ablk[s], aoff[s] = pool.tail_slot(sid)
+        logits, pool.k_pool, pool.v_pool = self._jit_paged_decode(
+            self.params, self._put(tok), pool.k_pool, pool.v_pool,
+            self._put(tables), self._put(pos), self._put(ablk),
+            self._put(aoff))
+        for s in slot_tokens:
+            pool.append_token(self.slots[s].session_id)
+            self.slots[s].length += 1
+        self.decode_steps += 1
+        return logits
 
     def park_session(self, sid: str) -> bool:
         """Session pauses for a tool call.  Paged mode: metadata-only —
@@ -367,8 +386,9 @@ class Engine:
                   n_tokens: int) -> bool:
         """Land an exported KV prefix into this engine's pool.  Returns
         False when the pool has no room (caller evicts and retries, or
-        abandons the copy)."""
-        ok = self.pool.park(sid, k, v, n_tokens)
+        abandons the copy).  The KV may come from another device: it
+        lands on this engine's own first."""
+        ok = self.pool.park(sid, self._put(k), self._put(v), n_tokens)
         if ok:
             self.migration_copy_bytes += self.pool.session_bytes(sid)
         return ok
@@ -400,6 +420,7 @@ class Engine:
         the full context parks fresh.  Returns False when the parked
         population would overflow nominal capacity — the runtime evicts
         and retries, or cancels the handoff."""
+        k, v = self._put(k), self._put(v)
         if append:
             ok = self.pool.extend_parked(sid, k, v, n_tokens)
         else:

@@ -37,7 +37,8 @@ import numpy as np
 class PagedKVPool:
     def __init__(self, n_layers: int, num_blocks: int, block_size: int,
                  n_kv_heads: int, head_dim: int, dtype=jnp.bfloat16,
-                 headroom_blocks: int = 0):
+                 headroom_blocks: int = 0,
+                 device: Optional[jax.Device] = None):
         self.L = n_layers
         # nominal (policy-visible) capacity: what WA-LRU/TTL budget against
         self.num_blocks = num_blocks
@@ -48,14 +49,21 @@ class PagedKVPool:
         self.dh = head_dim
         shape = (n_layers, self.total_blocks, block_size, n_kv_heads,
                  head_dim)
-        self.k_pool = jnp.zeros(shape, dtype)
-        self.v_pool = jnp.zeros(shape, dtype)
+        # committed to the owning engine's device, so a scatter of KV
+        # from any other device fails loudly instead of moving the pool
+        self.device = device if device is not None else jax.devices()[0]
+        self.k_pool = jnp.zeros(shape, dtype, device=self.device)
+        self.v_pool = jnp.zeros(shape, dtype, device=self.device)
         self.free: List[int] = list(range(self.total_blocks))
         self.tables: Dict[str, List[int]] = {}
         self.lens: Dict[str, int] = {}
         # slot-bound sessions: their blocks live in the headroom and are
         # invisible to the parked-capacity accounting below
         self.resident: Set[str] = set()
+
+    def _put(self, ids) -> jnp.ndarray:
+        """int32 block/offset indices on the pool's own device."""
+        return jax.device_put(np.asarray(ids, np.int32), self.device)
 
     # -- accounting ------------------------------------------------------
     @property
@@ -179,8 +187,8 @@ class PagedKVPool:
         for _ in range(need):
             tbl.append(self.free.pop())
         tok = np.arange(start, end)
-        bids = jnp.asarray([tbl[i] for i in tok // self.block], jnp.int32)
-        offs = jnp.asarray(tok % self.block, jnp.int32)
+        bids = self._put([tbl[i] for i in tok // self.block])
+        offs = self._put(tok % self.block)
         kd = k[:, :n_new].astype(self.k_pool.dtype)
         vd = v[:, :n_new].astype(self.v_pool.dtype)
         self.k_pool = self.k_pool.at[:, bids, offs].set(kd)
@@ -275,7 +283,7 @@ class PagedKVPool:
             v = v[:, :n_tokens]
         kb = k.reshape(self.L, nb, self.block, self.K, self.dh)
         vb = v.reshape(self.L, nb, self.block, self.K, self.dh)
-        idx = jnp.asarray(blocks, jnp.int32)
+        idx = self._put(blocks)
         self.k_pool = self.k_pool.at[:, idx].set(kb)
         self.v_pool = self.v_pool.at[:, idx].set(vb)
         self.tables[sid] = blocks
@@ -290,7 +298,7 @@ class PagedKVPool:
         blocks = self.tables.get(sid)
         if blocks is None:
             return None
-        idx = jnp.asarray(blocks, jnp.int32)
+        idx = self._put(blocks)
         k = self.k_pool[:, idx].reshape(self.L, -1, self.K, self.dh)
         v = self.v_pool[:, idx].reshape(self.L, -1, self.K, self.dh)
         n = self.lens[sid]

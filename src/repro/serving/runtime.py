@@ -82,6 +82,7 @@ import os
 import random
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.configs.base import ModelConfig
@@ -285,10 +286,13 @@ class ServingRuntime:
                  trace=None):
         self.cfg = cfg
         self.params = params
+        # one params replica per device, shared by the engines placed there
+        self._replicas: Dict[jax.Device, object] = {}
         self.engines = engines if engines is not None else [
-            Engine(cfg, params, n_slots=n_slots, max_len=max_len,
-                   pool_blocks=pool_blocks, paged=paged)
-            for _ in range(n_workers)]
+            Engine(cfg, self._replica(w), n_slots=n_slots, max_len=max_len,
+                   pool_blocks=pool_blocks, paged=paged,
+                   device=self._device(w))
+            for w in range(n_workers)]
         self.n_workers = len(self.engines)
         self.n_slots = self.engines[0].n_slots
         pool = self.engines[0].pool
@@ -443,6 +447,19 @@ class ServingRuntime:
     def _tr_instant(self, track: str, name: str, **meta) -> None:
         if self.tracer is not None:
             self.tracer.instant(track, name, self.ev.now, **meta)
+
+    # -- placement ------------------------------------------------------
+    @staticmethod
+    def _device(w: int) -> jax.Device:
+        """Engine ``w`` owns one device: replicas spread one per chip."""
+        devs = jax.devices()
+        return devs[w % len(devs)]
+
+    def _replica(self, w: int):
+        dev = self._device(w)
+        if dev not in self._replicas:
+            self._replicas[dev] = jax.device_put(self.params, dev)
+        return self._replicas[dev]
 
     # -- submission -----------------------------------------------------
     def submit(self, req,
@@ -1609,11 +1626,12 @@ class ServingRuntime:
         model's jitted functions (module ``_JIT_CACHE``) so joining
         costs no recompilation."""
         ref = self.engines[0]
-        eng = Engine(self.cfg, self.params, n_slots=ref.n_slots,
+        new_w = len(self.engines)
+        eng = Engine(self.cfg, self._replica(new_w), n_slots=ref.n_slots,
                      max_len=ref.max_len,
                      pool_blocks=ref.pool.num_blocks,
                      block_size=ref.pool.block, env=ref.env,
-                     paged=ref.paged)
+                     paged=ref.paged, device=self._device(new_w))
         self.engines.append(eng)
         # elastic capacity always joins the DECODE side: prefill-pool
         # sizing is a deployment-time choice (roles at construction)

@@ -91,7 +91,8 @@ def test_paged_decode_step_matches_ref():
     aoff = jnp.asarray([2, 2, 0, 3], jnp.int32)
 
     out, kp, vp = ops.paged_decode_step(q, k_new, v_new, k_pool, v_pool,
-                                        tables, lens, ablk, aoff)
+                                        tables, lens, ablk, aoff,
+                                        interpret=True)
     kp_ref, vp_ref = k_pool, v_pool
     for b in (0, 1, 3):                                # row 2 dropped
         kp_ref = kp_ref.at[:, ablk[b], aoff[b]].set(k_new[:, b])
