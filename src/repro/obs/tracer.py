@@ -15,6 +15,13 @@ across processes with different ``PYTHONHASHSEED``.  The tracer only
 keeps a traced run's ``summarize()`` byte-identical to the untraced
 run (asserted by the traced CI smoke leg).
 
+Wall-clock spans: a caller that measures real time (the serving
+engine, ``Engine(tracer=...)``) builds ``Tracer(clock=...)`` with the
+clock injected and records through ``span``, which stamps both ends
+with that clock.  The tracer has no clock of its own: the caller
+chooses it, so virtual-time users, which pass ``t`` explicitly, are
+unchanged.  One tracer holds one clock; the two never mix.
+
 Conservation: a well-hooked substrate closes every span it opens —
 ``check_closed()`` raises listing any still-open span, and the
 trace-conservation test suite reconciles span counts against event
@@ -23,24 +30,26 @@ with ``status="cancelled"``, not leak them).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import json
-from typing import Dict, List, Optional
+from typing import Callable, Dict, Iterator, List, Optional
 
 ROOT = -1                       # parent_id of top-level spans
 
 
 @dataclasses.dataclass
 class Span:
-    """One virtual-time interval (or instant) on a track.
+    """One interval (or instant) on a track, on its tracer's clock.
 
     ``status`` is ``"open"`` until ``end`` stamps the outcome: ``"ok"``
     for the normal path, or an explicit abnormal exit — ``"cancelled"``
     (fault killed the attempt), ``"preempted"`` (AFS parked the decode
     mid-step), ``"stolen"`` (left the queue for migration),
-    ``"requeued"`` (engine failure drained the queue).  Instants are
-    born closed."""
+    ``"requeued"`` (engine failure drained the queue), ``"error"``
+    (the block of a ``Tracer.span`` raised).  Instants are born
+    closed."""
     span_id: int
     parent_id: int
     track: str
@@ -69,9 +78,11 @@ class Span:
 
 
 class Tracer:
-    """Append-only span recorder on the virtual clock."""
+    """Append-only span recorder on the virtual clock, or on the
+    ``clock`` its caller injects for ``span``."""
 
-    def __init__(self) -> None:
+    def __init__(self, clock: Optional[Callable[[], float]] = None) -> None:
+        self.clock = clock
         self.spans: List[Span] = []
         self._by_id: Dict[int, Span] = {}
         # insertion-ordered open-span registry (a dict, not a set: the
@@ -125,6 +136,23 @@ class Tracer:
         sid = self.begin(track, name, t0, parent=parent, **meta)
         self.end(sid, t1)
         return sid
+
+    @contextlib.contextmanager
+    def span(self, track: str, name: str, parent: int = ROOT,
+             **meta) -> Iterator[int]:
+        """Record the ``with`` block as one span stamped by the tracer's
+        clock; yields the span id.  The span closes even when the block
+        raises, with status ``"error"``."""
+        if self.clock is None:
+            raise ValueError("Tracer.span needs a tracer built with a "
+                             "clock: Tracer(clock=...)")
+        sid = self.begin(track, name, self.clock(), parent=parent, **meta)
+        status = "error"
+        try:
+            yield sid
+            status = "ok"
+        finally:
+            self.end(sid, self.clock(), status=status)
 
     def note(self, span_id: int, **meta) -> None:
         """Attach late metadata to a live or closed span (e.g. the

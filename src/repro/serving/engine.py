@@ -16,12 +16,22 @@ copies.  Both modes share the same prefill, the same policy-visible
 capacity arithmetic, and (by construction of the masked attention) emit
 bit-identical token ids — `tests/test_paged_decode.py` gates this per
 architecture family.
+
+``Engine(tracer=Tracer(clock=...))`` records the engine's own spans on
+track ``engine`` (``engine.prefill`` and ``engine.decode``, each split
+into the host's preparation, the jitted dispatch, the bookkeeping and,
+for decode, the wait on the device), stamped by the tracer's clock and
+entered under the same names as ``jax.profiler.TraceAnnotation``s, so a
+profiler trace holds them on the device's clock.  Each span's
+``compiles`` meta counts the backend compiles inside it.  Without a
+tracer the engine records nothing (docs/OBSERVABILITY.md).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +40,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.models import lm
 from repro.models.sharding import ShardingEnv
+from repro.obs.tracer import ROOT, Tracer
 from repro.serving.kvcache import PagedKVPool
 
 
@@ -87,6 +98,66 @@ def _jitted_fns(cfg: ModelConfig, env: ShardingEnv):
     return fns
 
 
+class _Compiles:
+    """Backend compiles in this process.  JAX's event listeners are
+    process-wide and cannot be removed, so the count is too: a span
+    reads its difference across the span."""
+    n = 0
+    _installed = False
+
+    @classmethod
+    def install(cls) -> None:
+        if not cls._installed:
+            jax.monitoring.register_event_duration_secs_listener(cls._on)
+            cls._installed = True
+
+    @classmethod
+    def _on(cls, event: str, duration: float, **_) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            cls.n += 1
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+class _NoSpans:
+    """What an engine without a tracer records: nothing."""
+
+    def __call__(self, name: str, **meta):
+        return _NO_SPAN
+
+    def note(self, **meta) -> None:
+        pass
+
+
+class _Spans:
+    """The engine's spans on its tracer's clock, each also entered as a
+    profiler annotation of the same name; a span opened inside another
+    is its child."""
+
+    def __init__(self, tracer: Tracer):
+        _Compiles.install()
+        self.tracer = tracer
+        self.open = ROOT
+
+    @contextlib.contextmanager
+    def __call__(self, name: str, **meta):
+        parent, compiles = self.open, _Compiles.n
+        with jax.profiler.TraceAnnotation(name), \
+                self.tracer.span("engine", name, parent=parent,
+                                 **meta) as sid:
+            self.open = sid
+            try:
+                yield
+            finally:
+                self.open = parent
+                self.tracer.note(sid, compiles=_Compiles.n - compiles)
+
+    def note(self, **meta) -> None:
+        """Attach meta to the innermost open span."""
+        self.tracer.note(self.open, **meta)
+
+
 def serving_env() -> ShardingEnv:
     """The single-device sharding environment engines serve under."""
     return ShardingEnv(None, opts={"remat": False, "sp": False,
@@ -105,7 +176,8 @@ class Engine:
     def __init__(self, cfg: ModelConfig, params, *, n_slots: int = 4,
                  max_len: int = 512, pool_blocks: int = 64,
                  block_size: int = 16, env: Optional[ShardingEnv] = None,
-                 paged: bool = True, device: Optional[jax.Device] = None):
+                 paged: bool = True, device: Optional[jax.Device] = None,
+                 tracer: Optional[Tracer] = None):
         assert not cfg.enc_dec and cfg.family in ("dense", "moe", "vlm"), \
             "engine demo supports decoder-only KV families"
         assert not cfg.use_mla, \
@@ -155,6 +227,9 @@ class Engine:
         # prefill->decode handoff transport (disaggregated pools):
         # counted separately from migration so the A/B stays legible
         self.handoff_copy_bytes = 0
+        # wall-clock spans: the caller injects the tracer and its clock
+        self.tracer = tracer
+        self._spans = _Spans(tracer) if tracer is not None else _NoSpans()
 
         (self._jit_decode, self._jit_prefill,
          self._jit_paged_decode) = _jitted_fns(self.cfg, self.env)
@@ -184,8 +259,9 @@ class Engine:
         self.cache["v"] = self.cache["v"].at[:, slot].set(v)
         self.slots[slot].length = length
 
-    def _prefill_kv(self, tokens: np.ndarray):
-        """Prefill ``tokens`` and return (k, v) of shape (L, n, K, dh).
+    def _prefill_into(self, tokens: np.ndarray, land: Callable):
+        """Prefill ``tokens`` and hand their K/V, each (L, n, K, dh), to
+        ``land(k, v)``; returns what ``land`` returns.
 
         Token length is padded up to the compile quantum — lcm(32-token
         bucket, block size) — so the jitted prefill compiles O(max_len /
@@ -197,11 +273,15 @@ class Engine:
         pad_to = min(self.max_len, -(-n // self._prefill_quantum)
                      * self._prefill_quantum)
         pad_to = max(pad_to, n)
-        padded = np.zeros(pad_to, np.int32)
-        padded[:n] = tokens
-        _, cache = self._jit_prefill(self.params, self._put(padded[None]),
-                                     pad_to=pad_to)
-        return cache["k"][:, 0, :n], cache["v"][:, 0, :n]
+        self._spans.note(n=n, pad_to=pad_to)
+        with self._spans("engine.prefill.prep"):
+            padded = np.zeros(pad_to, np.int32)
+            padded[:n] = tokens
+            ids = self._put(padded[None])
+        with self._spans("engine.prefill.dispatch"):
+            _, cache = self._jit_prefill(self.params, ids, pad_to=pad_to)
+        with self._spans("engine.prefill.kv_write"):
+            return land(cache["k"][:, 0, :n], cache["v"][:, 0, :n])
 
     # -- public API ------------------------------------------------------------
     def start_session(self, sid: str, tokens: np.ndarray,
@@ -214,12 +294,13 @@ class Engine:
         if slot is None:
             return None
         tokens = np.asarray(tokens, np.int32)
-        if self.paged:
-            self._admit_paged(sid, tokens, cached_hit)
-            self.slots[slot] = SlotState(sid, len(tokens))
-        else:
-            self._admit_gather(slot, sid, tokens, cached_hit)
-            self.slots[slot].session_id = sid
+        with self._spans("engine.prefill", sid=sid, n=0, pad_to=0):
+            if self.paged:
+                self._admit_paged(sid, tokens, cached_hit)
+                self.slots[slot] = SlotState(sid, len(tokens))
+            else:
+                self._admit_gather(slot, sid, tokens, cached_hit)
+                self.slots[slot].session_id = sid
         return slot
 
     def _admit_paged(self, sid: str, tokens: np.ndarray,
@@ -230,18 +311,20 @@ class Engine:
         full context into blocks.  No gather, no slot copy — resume-copy
         bytes stay 0."""
         pool = self.pool
+
+        def land(k, v):
+            pool.extend(sid, k, v, bucket=self._prefill_quantum)
+
         if cached_hit and pool.has(sid):
             n = pool.lens[sid]
             pool.mark_resident(sid)
             delta = tokens[n:]
             if len(delta):
-                dk, dv = self._prefill_kv(delta)
-                pool.extend(sid, dk, dv, bucket=self._prefill_quantum)
+                self._prefill_into(delta, land)
                 self.prefill_tokens += len(delta)
         else:
             pool.alloc(sid)
-            k, v = self._prefill_kv(tokens)
-            pool.extend(sid, k, v, bucket=self._prefill_quantum)
+            self._prefill_into(tokens, land)
             self.prefill_tokens += len(tokens)
             self.regen_tokens += len(tokens)
 
@@ -256,22 +339,33 @@ class Engine:
             delta = tokens[n:]
             self.pool.free_session(sid)
             if len(delta):
-                dk, dv = self._prefill_kv(delta)
-                k = jnp.concatenate([k, dk], axis=1)
-                v = jnp.concatenate([v, dv], axis=1)
+                self._prefill_into(delta, lambda dk, dv: self._write_slot(
+                    slot, jnp.concatenate([k, dk], axis=1),
+                    jnp.concatenate([v, dv], axis=1), len(tokens)))
                 self.prefill_tokens += len(delta)
-            self._write_slot(slot, k, v, len(tokens))
+            else:
+                self._write_slot(slot, k, v, len(tokens))
         else:
-            k, v = self._prefill_kv(tokens)
+            self._prefill_into(tokens, lambda k, v: self._write_slot(
+                slot, k, v, len(tokens)))
             self.prefill_tokens += len(tokens)
             self.regen_tokens += len(tokens)
-            self._write_slot(slot, k, v, len(tokens))
 
     def decode(self, slot_tokens: Dict[int, int], n_steps: int = 1,
                greedy: bool = True) -> Dict[int, List[int]]:
         """Run `n_steps` batched decode steps for the given slots.
         slot_tokens: {slot: next input token id}.  Returns generated ids
         per slot."""
+        if self.tracer is None:
+            return self._decode(slot_tokens, n_steps)
+        # keys the first step's new tokens attend: each row's context
+        # with its new token
+        keys = sum(self.slots[s].length + 1 for s in slot_tokens)
+        with self._spans("engine.decode", rows=len(slot_tokens), keys=keys):
+            return self._decode(slot_tokens, n_steps)
+
+    def _decode(self, slot_tokens: Dict[int, int],
+                n_steps: int) -> Dict[int, List[int]]:
         if self.paged:
             return self._decode_paged(slot_tokens, n_steps)
         out: Dict[int, List[int]] = {s: [] for s in slot_tokens}
@@ -302,7 +396,8 @@ class Engine:
         cur = dict(slot_tokens)
         for _ in range(n_steps):
             logits = self.paged_step_logits(cur)
-            nxt = np.asarray(jnp.argmax(logits[:, 0, :], axis=-1))
+            with self._spans("engine.decode.sync"):
+                nxt = np.asarray(jnp.argmax(logits[:, 0, :], axis=-1))
             for s in cur:
                 out[s].append(int(nxt[s]))
                 cur[s] = int(nxt[s])
@@ -313,27 +408,31 @@ class Engine:
         append each row's K/V into its tail block, and return the
         (n_slots, 1, vocab) logits (idle rows are garbage)."""
         pool = self.pool
-        tok = np.zeros((self.n_slots, 1), np.int32)
-        pos = np.zeros((self.n_slots,), np.int32)
-        tables = np.zeros((self.n_slots, self.max_nb), np.int32)
-        ablk = np.full((self.n_slots,), pool.total_blocks, np.int32)
-        aoff = np.zeros((self.n_slots,), np.int32)
-        for s, t in slot_tokens.items():
-            sid = self.slots[s].session_id
-            pool.ensure_tail_room(sid)
-            tok[s, 0] = t
-            pos[s] = self.slots[s].length
-            tbl = pool.tables[sid]
-            tables[s, :len(tbl)] = tbl
-            ablk[s], aoff[s] = pool.tail_slot(sid)
-        logits, pool.k_pool, pool.v_pool = self._jit_paged_decode(
-            self.params, self._put(tok), pool.k_pool, pool.v_pool,
-            self._put(tables), self._put(pos), self._put(ablk),
-            self._put(aoff))
-        for s in slot_tokens:
-            pool.append_token(self.slots[s].session_id)
-            self.slots[s].length += 1
-        self.decode_steps += 1
+        with self._spans("engine.decode.prep"):
+            tok = np.zeros((self.n_slots, 1), np.int32)
+            pos = np.zeros((self.n_slots,), np.int32)
+            tables = np.zeros((self.n_slots, self.max_nb), np.int32)
+            ablk = np.full((self.n_slots,), pool.total_blocks, np.int32)
+            aoff = np.zeros((self.n_slots,), np.int32)
+            for s, t in slot_tokens.items():
+                sid = self.slots[s].session_id
+                pool.ensure_tail_room(sid)
+                tok[s, 0] = t
+                pos[s] = self.slots[s].length
+                tbl = pool.tables[sid]
+                tables[s, :len(tbl)] = tbl
+                ablk[s], aoff[s] = pool.tail_slot(sid)
+            tok, tables, pos, ablk, aoff = (
+                self._put(a) for a in (tok, tables, pos, ablk, aoff))
+        with self._spans("engine.decode.dispatch"):
+            logits, pool.k_pool, pool.v_pool = self._jit_paged_decode(
+                self.params, tok, pool.k_pool, pool.v_pool, tables, pos,
+                ablk, aoff)
+        with self._spans("engine.decode.book"):
+            for s in slot_tokens:
+                pool.append_token(self.slots[s].session_id)
+                self.slots[s].length += 1
+            self.decode_steps += 1
         return logits
 
     def park_session(self, sid: str) -> bool:
@@ -405,8 +504,10 @@ class Engine:
         fit — the PrefillScheduler gates admission on ``can_fit`` so
         this only trips under races it then defers."""
         delta = np.asarray(tokens[start:], np.int32)
-        dk, dv = self._prefill_kv(delta)
-        if not self.pool.park(sid, dk, dv, len(delta)):
+        with self._spans("engine.prefill", sid=sid, n=0, pad_to=0):
+            parked = self._prefill_into(delta, lambda dk, dv: self.pool.park(
+                sid, dk, dv, len(delta)))
+        if not parked:
             return False
         self.prefill_tokens += len(delta)
         if start == 0:
