@@ -15,5 +15,7 @@ Every op compiles for the TPU by default; ``interpret=True`` runs the
 kernel body on any backend.  All are validated in interpret mode on the CPU
 against ref.py across shape/dtype sweeps (tests/test_kernels.py), and the
 paged decode kernel is compiled ahead of time for a v5e chip
-(tests/test_chip_compile.py).
+(tests/test_chip_compile.py).  Only paged_attention is on the served
+path: the engine's paged decode attends with it on a TPU
+(models.layers.gqa_attention_decode_paged).
 """

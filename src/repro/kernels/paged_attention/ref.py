@@ -1,19 +1,18 @@
 """Pure-jnp oracle: gather pages to contiguous KV, run dense decode."""
 from __future__ import annotations
 
-import jax.numpy as jnp
-
 from repro.models.layers import decode_attention
 
 
-def paged_decode_ref(q, k_pool, v_pool, block_tables, lens):
-    """q: (B, H, dh); pools: (num_blocks, block, K, dh);
-    block_tables: (B, nb); lens: (B,).  Returns (B, H, dh)."""
+def paged_decode_ref(q, k_pool, v_pool, layer, block_tables, lens, *,
+                     window: int = 0):
+    """q: (B, H, dh); pools: (L, num_blocks, block, K, dh); layer: the
+    layer to read; block_tables: (B, nb); lens: (B,) keys per row, the
+    new token's included.  Returns (B, H, dh); a row of length 0 is
+    garbage here."""
     B, H, dh = q.shape
-    _, block, K, _ = k_pool.shape
-    k = k_pool[block_tables]            # (B, nb, block, K, dh)
-    v = v_pool[block_tables]
-    k = k.reshape(B, -1, K, dh)
-    v = v.reshape(B, -1, K, dh)
-    out = decode_attention(q[:, None], k, v, lens - 1)
+    K = k_pool.shape[3]
+    k = k_pool[layer][block_tables].reshape(B, -1, K, dh)
+    v = v_pool[layer][block_tables].reshape(B, -1, K, dh)
+    out = decode_attention(q[:, None], k, v, lens - 1, window=window)
     return out[:, 0]

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.kernels.paged_attention.kernel import paged_decode_attention
 from repro.models.sharding import ShardingEnv
 
 F32 = jnp.float32
@@ -332,22 +333,6 @@ def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0):
     return o.reshape(B, 1, H, v_cache.shape[-1]).astype(q.dtype)
 
 
-def paged_kv_update(k_pool, v_pool, k_new, v_new, block_ids, offsets):
-    """Write one decode step's K/V per batch row into paged pool blocks.
-
-    k_pool/v_pool: (num_blocks, block, K, dh) — ONE layer's blocks;
-    k_new/v_new: (B, 1, K, dh); block_ids/offsets: (B,) int32 append
-    destinations.  Rows whose block id is out of range are dropped —
-    idle batch rows pass ``num_blocks`` as a sentinel, so a partially
-    occupied continuous batch never writes stale KV anywhere.
-    """
-    kp = k_pool.at[block_ids, offsets].set(
-        k_new[:, 0].astype(k_pool.dtype), mode="drop")
-    vp = v_pool.at[block_ids, offsets].set(
-        v_new[:, 0].astype(v_pool.dtype), mode="drop")
-    return kp, vp
-
-
 def paged_kv_gather(k_pool, v_pool, tables):
     """Gather per-row block tables to a contiguous (B, nb*block, K, dh)
     view.  With nb*block equal to the gather-mode cache's max_len this
@@ -365,13 +350,19 @@ def paged_kv_gather(k_pool, v_pool, tables):
     return k, v
 
 
-def gqa_attention_decode_paged(x, p, cfg, env, k_pool, v_pool, tables,
-                               pos, block_ids, offsets):
+def gqa_attention_decode_paged(x, p, cfg, env, k_pool, v_pool, layer,
+                               tables, pos, block_ids, offsets, *,
+                               kernel: bool = False):
     """One-token decode over pool blocks: the twin of
     ``gqa_attention_decode`` with the contiguous (B, S, K, dh) cache
-    replaced by (pool, block-table) pairs.  Appends the new token's K/V
-    into each row's tail block, then attends over the gathered block
-    view.  Returns (y, k_pool, v_pool)."""
+    replaced by (pool, block-table) pairs.  ``k_pool``/``v_pool`` are
+    the whole (L, num_blocks, block, K, dh) pool and ``layer`` the layer
+    this call reads and writes.  Writes the new token's K/V in place at
+    ``[layer, block_ids, offsets]`` (an out-of-range block id, an idle
+    row's sentinel, writes nothing), then attends: with ``kernel`` the
+    Pallas block-table kernel reads each row's live blocks in place;
+    otherwise the reference gathers every row out to ``max_len`` for
+    ``decode_attention``.  Returns (y, k_pool, v_pool)."""
     B = x.shape[0]
     pos_b = jnp.broadcast_to(jnp.asarray(pos), (B,))
     q = jnp.einsum("bsd,dhx->bshx", x, p["wq"])
@@ -382,10 +373,17 @@ def gqa_attention_decode_paged(x, p, cfg, env, k_pool, v_pool, tables,
         k = rms_norm(k, p["knorm"], cfg.norm_eps)
     q = apply_rope(q, pos_b[:, None], cfg.rope_theta)
     k = apply_rope(k, pos_b[:, None], cfg.rope_theta)
-    k_pool, v_pool = paged_kv_update(k_pool, v_pool, k, v, block_ids,
-                                     offsets)
-    kg, vg = paged_kv_gather(k_pool, v_pool, tables)
-    y = decode_attention(q, kg, vg, pos_b, window=cfg.sliding_window)
+    k_pool = k_pool.at[layer, block_ids, offsets].set(
+        k[:, 0].astype(k_pool.dtype), mode="drop")
+    v_pool = v_pool.at[layer, block_ids, offsets].set(
+        v[:, 0].astype(v_pool.dtype), mode="drop")
+    if kernel:
+        lens = jnp.where(block_ids < k_pool.shape[1], pos_b + 1, 0)
+        y = paged_decode_attention(q[:, 0], k_pool, v_pool, layer, tables,
+                                   lens, window=cfg.sliding_window)[:, None]
+    else:
+        kg, vg = paged_kv_gather(k_pool[layer], v_pool[layer], tables)
+        y = decode_attention(q, kg, vg, pos_b, window=cfg.sliding_window)
     return jnp.einsum("bshx,hxd->bsd", y, p["wo"]), k_pool, v_pool
 
 

@@ -438,15 +438,16 @@ def _uniform_decode_block(x, lp, kc, vc, cfg, env, pos):
     return L.pin_bf16(x + L.pin_bf16(y)), kc, vc
 
 
-def _uniform_decode_block_paged(x, lp, kp, vp, tables, pos, block_ids,
-                                offsets, cfg, env):
+def _uniform_decode_block_paged(x, lp, kp, vp, layer, tables, pos,
+                                block_ids, offsets, cfg, env, kernel):
     """Twin of ``_uniform_decode_block`` attending over pool blocks
     instead of a contiguous per-slot cache; identical residual-stream
     pinning so both paths round the stream bit-identically."""
     h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
     y, kp, vp = L.gqa_attention_decode_paged(h, lp["attn"], cfg, env, kp,
-                                             vp, tables, pos, block_ids,
-                                             offsets)
+                                             vp, layer, tables, pos,
+                                             block_ids, offsets,
+                                             kernel=kernel)
     x = L.pin_bf16(x + L.pin_bf16(y))
     h = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
     if "router" in lp["mlp"]:
@@ -799,7 +800,7 @@ def decode_step(params, tokens, cache, pos, cfg: ModelConfig,
 
 def decode_step_paged(params, tokens, k_pool, v_pool, tables, pos,
                       block_ids, offsets, cfg: ModelConfig,
-                      env: ShardingEnv):
+                      env: ShardingEnv, *, kernel: bool = False):
     """Paged twin of ``decode_step``: the contiguous ``cache`` dict is
     replaced by the serving pool's block arrays plus per-row block
     tables, so parked/resident KV never moves — decode attends over it
@@ -810,19 +811,28 @@ def decode_step_paged(params, tokens, k_pool, v_pool, tables, pos,
     padded positions are masked); pos: (B,) position of the new token;
     block_ids/offsets: (B,) append destination of the new token's K/V
     (idle rows pass num_blocks as an out-of-range drop sentinel).
-    Covers the decoder-only GQA families the serving engine admits
-    (dense / moe / vlm).  Returns (logits (B,1,V), k_pool, v_pool)."""
+    The pools ride in the layer scan's carry, so each layer writes its
+    token in place and a caller that donates them gets them back in the
+    same buffers; ``kernel`` attends with the Pallas block-table kernel
+    (TPU), else with the gather reference (see
+    ``layers.gqa_attention_decode_paged``).  Covers the decoder-only GQA
+    families the serving engine admits (dense / moe / vlm).  Returns
+    (logits (B,1,V), k_pool, v_pool)."""
     assert not (cfg.enc_dec or cfg.use_mla or cfg.family == "ssm"
                 or cfg.attn_period), \
         "paged decode covers the uniform GQA-cache families"
     x = embed_tokens(params, tokens, cfg)
 
-    def body(x, xs):
-        lp, kp, vp = xs
+    def body(carry, xs):
+        x, kp, vp = carry
+        lp, layer = xs
         x, kp, vp = _uniform_decode_block_paged(
-            x, lp, kp, vp, tables, pos, block_ids, offsets, cfg, env)
-        return x, (kp, vp)
+            x, lp, kp, vp, layer, tables, pos, block_ids, offsets, cfg, env,
+            kernel)
+        return (x, kp, vp), None
 
-    x, ys = layer_scan(body, x, (params["layers"], k_pool, v_pool), env)
+    layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+    (x, k_pool, v_pool), _ = layer_scan(
+        body, (x, k_pool, v_pool), (params["layers"], layers), env)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return unembed(params, x, cfg), ys[0], ys[1]
+    return unembed(params, x, cfg), k_pool, v_pool

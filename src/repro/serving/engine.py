@@ -5,8 +5,11 @@ The engine executes REAL forward passes (jitted prefill / batched decode)
 against a model from the zoo.  In the default **paged** mode a session's
 KV lands in `PagedKVPool` blocks at admit (prefill scatters straight
 into blocks), the batched decode step attends over per-slot block tables
-and appends each new token's K/V into the tail block on device, and
-park/resume/preempt are pure metadata flips — zero device copies.  A
+and writes each new token's K/V in place into the tail block of the
+pool it is donated, and park/resume/preempt are pure metadata flips —
+zero device copies.  On a TPU the step attends with the Pallas
+block-table kernel, which reads only each row's live blocks; elsewhere
+with the gather reference (``layers.gqa_attention_decode_paged``).  A
 decode slot is just a batch-row binding, so co-residency is bounded by
 pool memory, not slot-cache memory.
 
@@ -52,23 +55,28 @@ from repro.serving.kvcache import PagedKVPool
 _PREFILL_BUCKET = 32
 
 # one jitted (decode, prefill, paged-decode) triple per (config,
-# sharding-options) — engines of the same model share compiled code
-# instead of each instance re-tracing through its own bound-method
+# sharding-options, platform) — engines of the same model share compiled
+# code instead of each instance re-tracing through its own bound-method
 # closures (a multi-engine runtime otherwise pays the full compile set
 # per engine)
 _JIT_CACHE: Dict[tuple, tuple] = {}
 
 
-def _jitted_fns(cfg: ModelConfig, env: ShardingEnv):
+def _jitted_fns(cfg: ModelConfig, env: ShardingEnv, platform: str):
+    """The engine's jitted steps for a device of ``platform``.  On a TPU
+    the paged decode attends with the Pallas block-table kernel; on any
+    other platform with the gather reference.  Both donate the pool."""
     if env.mesh is not None:
         key = None          # meshes aren't value-hashable: no sharing
     else:
-        key = (cfg, tuple(sorted(env.opts.items())))
+        key = (cfg, tuple(sorted(env.opts.items())), platform)
     try:
         fns = _JIT_CACHE.get(key) if key is not None else None
     except TypeError:       # unhashable opt value: no sharing
         key, fns = None, None
     if fns is None:
+        kernel = platform == "tpu"
+
         def decode_fn(params, tokens, cache, positions):
             return lm.decode_step(params, tokens, cache, positions, cfg,
                                   env)
@@ -88,11 +96,12 @@ def _jitted_fns(cfg: ModelConfig, env: ShardingEnv):
                             positions, block_ids, offsets):
             return lm.decode_step_paged(params, tokens, k_pool, v_pool,
                                         tables, positions, block_ids,
-                                        offsets, cfg, env)
+                                        offsets, cfg, env, kernel=kernel)
 
         fns = (jax.jit(decode_fn),
                jax.jit(prefill_fn, static_argnames=("pad_to",)),
-               jax.jit(paged_decode_fn))
+               # the round writes its tokens into the pool it is given
+               jax.jit(paged_decode_fn, donate_argnums=(2, 3)))
         if key is not None:
             _JIT_CACHE[key] = fns
     return fns
@@ -232,7 +241,11 @@ class Engine:
         self._spans = _Spans(tracer) if tracer is not None else _NoSpans()
 
         (self._jit_decode, self._jit_prefill,
-         self._jit_paged_decode) = _jitted_fns(self.cfg, self.env)
+         self._jit_paged_decode) = _jitted_fns(self.cfg, self.env,
+                                               self.device.platform)
+        # how decode attends: the paged kernel on a TPU, else XLA
+        self.attn = ("kernel" if paged and self.device.platform == "tpu"
+                     else "xla")
 
     def _put(self, x) -> jnp.ndarray:
         return jax.device_put(x, self.device)
@@ -359,9 +372,12 @@ class Engine:
         if self.tracer is None:
             return self._decode(slot_tokens, n_steps)
         # keys the first step's new tokens attend: each row's context
-        # with its new token
-        keys = sum(self.slots[s].length + 1 for s in slot_tokens)
-        with self._spans("engine.decode", rows=len(slot_tokens), keys=keys):
+        # with its new token; and the blocks that holds
+        lens = [self.slots[s].length + 1 for s in slot_tokens]
+        block = self.pool.block
+        with self._spans("engine.decode", rows=len(lens), keys=sum(lens),
+                         kv_blocks=sum(-(-n // block) for n in lens),
+                         attn=self.attn):
             return self._decode(slot_tokens, n_steps)
 
     def _decode(self, slot_tokens: Dict[int, int],

@@ -22,8 +22,8 @@ Two session populations share the arrays:
 
 Parking a resident session is metadata-only (a set flip, no copy); so
 is resuming a parked one (``mark_resident``).  The paged decode step
-(``models.lm.decode_step_paged``) appends each new token's K/V straight
-into the tail block on device.
+(``models.lm.decode_step_paged``) takes both arrays donated and writes
+each new token's K/V in place into its tail block on device.
 """
 from __future__ import annotations
 
@@ -304,9 +304,3 @@ class PagedKVPool:
         n = self.lens[sid]
         return k[:, :n], v[:, :n], n
 
-    def block_table_array(self, sid: str, max_blocks: int) -> np.ndarray:
-        """Padded int32 block table for the Pallas paged-decode kernel."""
-        blocks = self.tables.get(sid, [])
-        out = np.zeros((max_blocks,), np.int32)
-        out[:len(blocks)] = blocks
-        return out

@@ -105,10 +105,15 @@ def test_one_span_tree_per_call(model):
         assert sp.meta["n"] == n - 1
         assert sp.meta["pad_to"] >= n - 1
         assert sp.meta["pad_to"] % eng._prefill_quantum == 0
-    # decode meta: live rows and the keys their new tokens attend
+    # decode meta: live rows, the keys their new tokens attend, the
+    # blocks that holds and how it is attended (the CPU's reference)
+    block = eng.pool.block
     for r, sp in enumerate(tops[len(lengths):]):
         assert sp.meta["rows"] == len(lengths)
         assert sp.meta["keys"] == sum(n + r for n in lengths)
+        assert sp.meta["kv_blocks"] == sum(-(-(n + r) // block)
+                                           for n in lengths)
+        assert sp.meta["attn"] == "xla"
 
 
 def test_compiles_counted_inside_the_span(model):

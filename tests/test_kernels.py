@@ -1,5 +1,7 @@
 """Pallas kernel validation: shape/dtype sweeps vs ref.py oracles
 (interpret=True executes the kernel bodies on CPU)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -37,6 +39,29 @@ def test_flash_attention(B, Sq, Sk, H, K, D, dtype, causal, window):
 
 
 # --- paged decode attention ------------------------------------------------------
+def _paged_case(B, H, K, dh, block, nblocks, nb, dtype, L=2, seed=0):
+    """Random (L, nblocks, block, K, dh) pools, a query per row and
+    disjoint block tables."""
+    key = jax.random.PRNGKey(seed)
+    rng = np.random.RandomState(seed)
+    q = jax.random.normal(key, (B, H, dh), dtype)
+    kp = jax.random.normal(jax.random.fold_in(key, 1),
+                           (L, nblocks, block, K, dh), dtype)
+    vp = jax.random.normal(jax.random.fold_in(key, 2),
+                           (L, nblocks, block, K, dh), dtype)
+    tables = rng.permutation(nblocks)[:B * nb].reshape(B, nb)
+    return q, kp, vp, jnp.asarray(tables, jnp.int32)
+
+
+def _paged_err(out, ref, lens):
+    """Largest error over the rows with keys; a row of length 0 must
+    come back as zeros."""
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    live = np.asarray(lens) > 0
+    assert not out[~live].any()
+    return float(np.abs(out[live] - ref[live]).max())
+
+
 @pytest.mark.parametrize("B,H,K,dh,block,nblocks,nb", [
     (2, 4, 2, 64, 16, 32, 4),
     (3, 8, 8, 128, 32, 64, 3),
@@ -46,23 +71,70 @@ def test_flash_attention(B, Sq, Sk, H, K, D, dtype, causal, window):
 def test_paged_attention(B, H, K, dh, block, nblocks, nb, dtype):
     from repro.kernels.paged_attention.ops import paged_attention
     from repro.kernels.paged_attention.ref import paged_decode_ref
-    key = jax.random.PRNGKey(0)
-    rng = np.random.RandomState(0)
-    q = jax.random.normal(key, (B, H, dh), dtype)
-    kp = jax.random.normal(jax.random.fold_in(key, 1),
-                           (nblocks, block, K, dh), dtype)
-    vp = jax.random.normal(jax.random.fold_in(key, 2),
-                           (nblocks, block, K, dh), dtype)
-    tables = np.stack([rng.choice(nblocks, size=nb, replace=False)
-                       for _ in range(B)]).astype(np.int32)
-    lens = rng.randint(1, nb * block + 1, size=B).astype(np.int32)
-    out = paged_attention(q, kp, vp, jnp.asarray(tables),
-                          jnp.asarray(lens), interpret=True)
-    ref = paged_decode_ref(q, kp, vp, jnp.asarray(tables),
-                           jnp.asarray(lens))
-    err = float(jnp.max(jnp.abs(out.astype(jnp.float32) -
-                                ref.astype(jnp.float32))))
-    assert err < TOL[dtype], err
+    q, kp, vp, tables = _paged_case(B, H, K, dh, block, nblocks, nb, dtype)
+    lens = jnp.asarray(np.random.RandomState(1).randint(
+        1, nb * block + 1, size=B), jnp.int32)
+    out = paged_attention(q, kp, vp, 1, tables, lens, interpret=True)
+    ref = paged_decode_ref(q, kp, vp, 1, tables, lens)
+    assert _paged_err(out, ref, lens) < TOL[dtype]
+
+
+# lengths at and around block edges, an idle row and a full row
+RAGGED = [0, 1, 15, 16, 17, 6 * 16]
+
+
+@pytest.fixture
+def two_block_chunks(monkeypatch):
+    """The kernel reads 2 blocks a chunk, so the rows above take one
+    chunk, several and a short last one; returns the kernel, unjitted
+    (a jitted wrapper would keep its trace at the default chunk)."""
+    from repro.kernels.paged_attention import kernel
+    monkeypatch.setattr(kernel, "CHUNK_TOKENS", 32)
+    return functools.partial(kernel.paged_decode_attention, interpret=True)
+
+
+@pytest.mark.parametrize("G", [3, 4, 8])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_attention_ragged_rows_every_group_size(G, dtype,
+                                                      two_block_chunks):
+    from repro.kernels.paged_attention.ref import paged_decode_ref
+    K, nb = 2, 6
+    q, kp, vp, tables = _paged_case(len(RAGGED), G * K, K, 64, 16, 40, nb,
+                                    dtype, L=3, seed=G)
+    lens = jnp.asarray(RAGGED, jnp.int32)
+    for layer in range(3):
+        out = two_block_chunks(q, kp, vp, layer, tables, lens)
+        ref = paged_decode_ref(q, kp, vp, layer, tables, lens)
+        assert _paged_err(out, ref, lens) < TOL[dtype], layer
+
+
+@pytest.mark.parametrize("window", [1, 20, 40])
+def test_paged_attention_sliding_window(window, two_block_chunks):
+    """Only the last ``window`` keys count; blocks wholly before the
+    window are skipped."""
+    from repro.kernels.paged_attention.ref import paged_decode_ref
+    q, kp, vp, tables = _paged_case(len(RAGGED), 8, 2, 64, 16, 40, 6,
+                                    jnp.float32)
+    lens = jnp.asarray(RAGGED, jnp.int32)
+    out = two_block_chunks(q, kp, vp, 0, tables, lens, window=window)
+    ref = paged_decode_ref(q, kp, vp, 0, tables, lens, window=window)
+    assert _paged_err(out, ref, lens) < TOL[jnp.float32]
+
+
+def test_paged_attention_reads_no_block_past_a_row(two_block_chunks):
+    """Blocks outside a row's live span are never read: poisoning them
+    with NaN leaves every output finite and unchanged."""
+    q, kp, vp, tables = _paged_case(len(RAGGED), 8, 2, 64, 16, 40, 6,
+                                    jnp.float32)
+    lens = jnp.asarray(RAGGED, jnp.int32)
+    out = two_block_chunks(q, kp, vp, 0, tables, lens)
+    t = np.asarray(tables)
+    dead = [t[r, -(-n // 16):] for r, n in enumerate(RAGGED)]
+    dead = np.concatenate(dead + [np.setdiff1d(np.arange(40), t)])
+    kp = kp.at[:, dead].set(jnp.nan)
+    vp = vp.at[:, dead].set(jnp.nan)
+    poisoned = two_block_chunks(q, kp, vp, 0, tables, lens)
+    assert np.array_equal(np.asarray(out), np.asarray(poisoned))
 
 
 # --- rwkv6 -------------------------------------------------------------------------
