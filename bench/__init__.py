@@ -8,5 +8,8 @@ runtime).
 Cells, metrics and bounds are listed in ``BENCHMARK.json`` at the root of
 the checkout.  Each configuration (``configs/<name>.json``), traffic mix
 (``traffic/<name>.json``) and metric (``metrics/<name>.py``) lives in a
-file of its own that the harness finds by the name given there.
+file of its own that the harness finds by the name given there; each
+configuration names its model family (``families/<family>.py``: shapes,
+the program's configuration, the weights' layout, the plain reference
+and the counts of bytes and FLOPs), so a new architecture is new files.
 """

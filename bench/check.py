@@ -1,5 +1,6 @@
 """The comparison that decides ``correct``: served greedy tokens against
-the plain reference (``bench/reference.py``).
+the plain reference, the configuration's family's ``logits_at``
+(``bench/families``).
 
 A served request is its prompt and the tokens it decoded.  The reference
 reads the prompt followed by every decoded token but the last, and
@@ -11,12 +12,9 @@ gap over its sample."""
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Sequence
+from typing import Callable, Dict, List, Sequence
 
 import numpy as np
-
-from bench import reference
-from bench.dims import Dims
 
 SAMPLE_TOKENS = 320      # served tokens a run compares, at the least
 
@@ -52,30 +50,30 @@ def sample(steps: Sequence[ServedStep], rng: np.random.Generator,
     return out
 
 
-def gaps(weights: Dict, d: Dims, steps: Sequence[ServedStep], *,
-         control: str = "") -> np.ndarray:
-    """Per served token of ``steps``, the reference's best logit minus
-    its logit of the token scored.  With ``control`` (a precision of
-    ``reference.logits_at``) the token scored at each position is the
+def gaps(logits_at: Callable, weights: Dict, d, steps: Sequence[ServedStep],
+         *, control: str = "") -> np.ndarray:
+    """Per served token of ``steps``, the reference ``logits_at``'s best
+    logit minus its logit of the token scored.  With ``control`` (a
+    precision of ``logits_at``) the token scored at each position is the
     one the forward at that precision puts first (no decoding: the same
     streams)."""
     out = []
     for st in steps:
         tokens, rows = st.stream()
-        ref = reference.logits_at(weights, d, tokens, rows)
+        ref = logits_at(weights, d, tokens, rows)
         if control:
-            pick = reference.logits_at(weights, d, tokens, rows,
-                                       precision=control).argmax(-1)
+            pick = logits_at(weights, d, tokens, rows,
+                             precision=control).argmax(-1)
         else:
             pick = np.asarray(st.served)
         out.append(ref.max(-1) - ref[np.arange(len(pick)), pick])
     return np.concatenate(out) if out else np.zeros(0, np.float32)
 
 
-def widest_gap(weights: Dict, d: Dims, steps: Sequence[ServedStep], *,
-               control: str = "") -> float:
+def widest_gap(logits_at: Callable, weights: Dict, d,
+               steps: Sequence[ServedStep], *, control: str = "") -> float:
     """The run's number: the widest gap in the sample (infinite where
     the sample is empty or a logit is not finite)."""
-    g = gaps(weights, d, steps, control=control)
+    g = gaps(logits_at, weights, d, steps, control=control)
     return float(g.max()) if g.size and np.all(np.isfinite(g)) \
         else float("inf")

@@ -6,7 +6,7 @@ benchmark's own runs).
 For each seed, in one process, it serves the cell as a run does (a
 shorter window at the cell's own load), takes the same sample, and
 prints one JSON line with the widest gaps below the float32 reference's
-best logit of:
+best logit (the family's ``logits_at``) of:
 
   served     the tokens the program served (the number a run compares);
   control    the tokens that the reference in float8 e4m3 puts first at
@@ -31,16 +31,19 @@ ROOT = Path(__file__).resolve().parents[1]
 
 def readings(config, mix, seed, seconds, chips, log, device_peaks=None):
     from bench import check, weights as W
+    from bench.families import family_of
     from bench.harness import checked_sample, serve
     t0 = time.perf_counter()
     out, steps, d, devs = serve(config, mix, seed=seed, seconds=seconds,
                                 trace=False, chips=chips, metrics=[],
                                 t_process=t0, log=log,
                                 device_peaks=device_peaks)
-    weights = W.make_weights(d, seed, devs[0])
+    fam = family_of(config)
+    weights = W.make_weights(fam, d, seed, devs[0])
     sample = checked_sample(steps, seed)
-    served = check.widest_gap(weights, d, sample)
-    control = check.widest_gap(weights, d, sample, control="fp8")
+    served = check.widest_gap(fam.logits_at, weights, d, sample)
+    control = check.widest_gap(fam.logits_at, weights, d, sample,
+                               control="fp8")
     del weights
     return {"seed": seed, "requests": len(sample),
             "tokens": sum(len(s.served) for s in sample),

@@ -26,7 +26,14 @@ and ``warm_s`` seconds of the schedule itself.  The window's population
 is every request that arrives in it; the loop serves on past the close,
 with the schedule's later arrivals, until each of them has finished (at
 most ``DRAIN_S``).  Then the program's state is freed and the reference
-(``bench/check.py``) runs over a sample of what the window served."""
+(``bench/check.py``) runs over a sample of what the window served.
+
+What depends on the architecture (shapes, the program's configuration,
+the weights' layout, the reference and the counts of bytes and FLOPs)
+comes from the configuration's family (``bench/families``).  A traced
+run (``--trace 1``) builds the engine with a tracer, and hands the meta
+of each call's engine span and the window's spans to the metric
+readers; an untraced run builds it without one."""
 from __future__ import annotations
 
 import collections
@@ -40,6 +47,7 @@ import sys
 import tempfile
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -51,7 +59,7 @@ if str(ROOT / "src") not in sys.path:
 import jax  # noqa: E402
 
 from bench import check, trace_reduce, weights as W  # noqa: E402
-from bench.dims import Dims, dims_of  # noqa: E402
+from bench.families import family_of  # noqa: E402
 from bench.peaks import peaks  # noqa: E402
 from bench.traffic import Request, schedule, seed_words, sizes  # noqa: E402
 
@@ -80,6 +88,7 @@ class RoundRec:
     start: float
     end: float
     keys: List[int]              # keys each live row's new token attends
+    meta: dict = dataclasses.field(default_factory=dict)   # see RunRecord
 
 
 @dataclasses.dataclass
@@ -87,6 +96,7 @@ class PrefillRec:
     start: float                 # host span of the call (not synced:
     end: float                   # the next round's readback is)
     n: int                       # tokens prefilled
+    meta: dict = dataclasses.field(default_factory=dict)   # see RunRecord
 
 
 @dataclasses.dataclass
@@ -94,9 +104,15 @@ class RunRecord:
     """Everything a metric reader may read (``metrics/<name>.py``): per
     request its arrival, token times and sizes; per decode round and
     prefill its host span and sizes; the window's bounds; the compiles
-    inside it; and the reduced trace of a traced run.  A metric added
-    later reads these and needs no harness edit."""
-    dims: Dims
+    inside it; the configuration's family and shapes; and, in a traced
+    run, the reduced trace, the engine's spans that start in the window
+    (``spans``, ``repro.obs.tracer.Span`` on the host clock) and per
+    round and prefill the meta of the engine's span of that call
+    (``RoundRec.meta``: ``engine.decode``'s; ``PrefillRec.meta``:
+    ``engine.prefill``'s; ``{}`` untraced).  A metric added later reads
+    these and needs no harness edit."""
+    family: ModuleType           # bench/families/<family>.py
+    dims: object                 # the family's Dims
     peaks: dict
     t_process: float
     t0: float
@@ -107,6 +123,7 @@ class RunRecord:
     compiles_window: int
     trace: Optional[trace_reduce.TraceSummary] = None
     trace_pc: Optional[Tuple[float, float]] = None
+    spans: List = dataclasses.field(default_factory=list)
 
     @property
     def window_s(self) -> float:
@@ -123,6 +140,16 @@ class RunRecord:
     def in_trace(self, start: float, end: float) -> bool:
         return self.trace_pc is not None \
             and self.trace_pc[0] <= start and end <= self.trace_pc[1]
+
+
+def call_meta(tracer, since: int, name: str) -> dict:
+    """The meta of the first span named ``name`` that ``tracer`` recorded
+    from its ``since``-th span on: the engine's span of the call made
+    since then.  ``{}`` without a tracer."""
+    if tracer is None:
+        return {}
+    return next((dict(sp.meta) for sp in tracer.spans[since:]
+                 if sp.name == name), {})
 
 
 def percentile(values: Sequence[float], q: float) -> Optional[float]:
@@ -179,6 +206,8 @@ class Server:
 
     def run(self) -> None:
         eng = self.eng
+        tracer = eng.tracer
+        spans = tracer.spans if tracer is not None else []
         base = time.perf_counter()
         queue: collections.deque = collections.deque()
         live: Dict[int, ReqRec] = {}
@@ -213,15 +242,19 @@ class Server:
             while queue and eng.free_slot() is not None:
                 rec = queue.popleft()
                 prompt = rec.req.prompt
+                since = len(spans)
                 t = time.perf_counter()
                 slot = eng.start_session(rec.req.rid,
                                          np.asarray(prompt[:-1], np.int32),
                                          cached_hit=False)
                 live[slot], nxt[slot] = rec, prompt[-1]
                 end = time.perf_counter()
-                self.prefills.append(PrefillRec(t, end, len(prompt) - 1))
+                self.prefills.append(PrefillRec(
+                    t, end, len(prompt) - 1,
+                    call_meta(tracer, since, "engine.prefill")))
                 self._span("prefill", t, end)
             if live:
+                since = len(spans)
                 t = time.perf_counter()
                 out = eng.decode(dict(nxt), n_steps=1)
                 end = time.perf_counter()
@@ -235,7 +268,8 @@ class Server:
                     if rec.complete:
                         eng.release_session(rec.req.rid)
                         del live[slot], nxt[slot]
-                self.rounds.append(RoundRec(t, end, keys))
+                self.rounds.append(RoundRec(
+                    t, end, keys, call_meta(tracer, since, "engine.decode")))
                 self._span("round", t, end)
             elif i < len(self.schedule):
                 wake = base + self.schedule[i].arrival
@@ -276,16 +310,10 @@ class Server:
 # --------------------------------------------------------------------------
 # helpers
 # --------------------------------------------------------------------------
-def model_config(spec: dict, d: Dims):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-    return ModelConfig(name=spec["name"], family="dense",
-                       n_layers=d.n_layers, d_model=d.d_model,
-                       n_heads=d.n_heads, n_kv_heads=d.n_kv_heads,
-                       head_dim=d.head_dim, d_ff=d.d_ff, vocab=d.vocab,
-                       qk_norm=d.qk_norm, rope_theta=d.rope_theta,
-                       norm_eps=d.norm_eps,
-                       tie_embeddings=d.tie_embeddings)
+def model_config(spec: dict, d):
+    """The program's ``ModelConfig`` for a configuration file and its
+    shapes ``d``: its family's ``program_config``."""
+    return family_of(spec).program_config(spec, d)
 
 
 def check_layout(params, mcfg) -> None:
@@ -348,6 +376,7 @@ def serve(config: dict, mix: dict, *, seed: int, seconds: float,
     for the published table only where a test serves on a device that
     is not in it."""
     from repro.launch.compile_cache import enable_compile_cache
+    from repro.obs.tracer import Tracer
     from repro.serving.engine import Engine
 
     log = log or (lambda *a: None)
@@ -358,18 +387,20 @@ def serve(config: dict, mix: dict, *, seed: int, seconds: float,
     if chips != 1:
         raise ValueError("the serving loop drives one engine on one chip")
     kind = devs[0].device_kind
-    d = dims_of(config)
+    fam = family_of(config)
+    d = fam.dims_of(config)
     mcfg = model_config(config, d)
     eng_spec = config["engine"]
 
-    params = W.make_weights(d, seed, devs[0])
+    params = W.make_weights(fam, d, seed, devs[0])
     check_layout(params, mcfg)
-    log(f"weights made: {W.n_params(d)} params, "
+    log(f"weights made: {W.n_params(fam, d)} params, "
         f"{time.perf_counter() - t_process:.1f} s")
     eng = Engine(mcfg, params, n_slots=eng_spec["n_slots"],
                  max_len=eng_spec["max_len"],
                  pool_blocks=eng_spec["pool_blocks"],
-                 block_size=eng_spec["block_size"], device=devs[0])
+                 block_size=eng_spec["block_size"], device=devs[0],
+                 tracer=Tracer(clock=time.perf_counter) if trace else None)
     del params
     warm_shapes(eng, mix, d.vocab, seed)
     log(f"shapes warmed: {_Compiles.n} compiles, "
@@ -386,12 +417,15 @@ def serve(config: dict, mix: dict, *, seed: int, seconds: float,
     log(f"window: {srv.t1 - srv.t0:.2f} s, {len(srv.rounds)} rounds, "
         f"{len(srv.recs)} requests")
 
-    run = RunRecord(dims=d, peaks=device_peaks or peaks(kind),
+    run = RunRecord(family=fam, dims=d, peaks=device_peaks or peaks(kind),
                     t_process=t_process, t0=srv.t0,
                     t1=srv.t1, requests=srv.recs, rounds=srv.rounds,
                     prefills=srv.prefills,
                     compiles_window=srv.compiles1 - srv.compiles0,
                     trace_pc=srv.trace_pc)
+    if eng.tracer is not None:
+        run.spans = [sp for sp in eng.tracer.spans
+                     if srv.t0 <= sp.t0 < srv.t1]
     host_spans: List = []
     if tmp is not None:
         path = trace_reduce.find_trace(tmp)
@@ -441,9 +475,10 @@ def serve_and_check(config: dict, mix: dict, *, seed: int, **kw) -> dict:
     out, served, d, devs = serve(config, mix, seed=seed, **kw)
     log = kw.get("log") or (lambda *a: None)
     t = time.perf_counter()
-    weights = W.make_weights(d, seed, devs[0])
+    fam = family_of(config)
+    weights = W.make_weights(fam, d, seed, devs[0])
     sample = checked_sample(served, seed)
-    gap = check.widest_gap(weights, d, sample)
+    gap = check.widest_gap(fam.logits_at, weights, d, sample)
     del weights
     n_tok = sum(len(s.served) for s in sample)
     log(f"reference: {len(sample)} requests, {n_tok} tokens, "

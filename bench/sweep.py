@@ -20,9 +20,9 @@ import sys
 def sweep(config, mix, rates, seconds, seed, log, device_peaks=None):
     import jax
     from bench import weights as W
-    from bench.dims import dims_of
-    from bench.harness import (Server, _Compiles, load_reader, model_config,
-                               percentile, warm_shapes, RunRecord)
+    from bench.families import family_of
+    from bench.harness import (Server, _Compiles, load_reader, percentile,
+                               warm_shapes, RunRecord)
     from bench.peaks import peaks
     from bench.traffic import schedule
     from repro.launch.compile_cache import enable_compile_cache
@@ -32,9 +32,11 @@ def sweep(config, mix, rates, seconds, seed, log, device_peaks=None):
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     _Compiles.install()
     dev = jax.devices()[0]
-    d = dims_of(config)
+    fam = family_of(config)
+    d = fam.dims_of(config)
     e = config["engine"]
-    eng = Engine(model_config(config, d), W.make_weights(d, seed, dev),
+    eng = Engine(fam.program_config(config, d),
+                 W.make_weights(fam, d, seed, dev),
                  n_slots=e["n_slots"], max_len=e["max_len"],
                  pool_blocks=e["pool_blocks"], block_size=e["block_size"],
                  device=dev)
@@ -46,7 +48,8 @@ def sweep(config, mix, rates, seconds, seed, log, device_peaks=None):
                      warm_s=float(m["warm_s"]), seconds=seconds,
                      trace_dir=None)
         srv.run()
-        run = RunRecord(dims=d, peaks=device_peaks or peaks(dev.device_kind),
+        run = RunRecord(family=fam, dims=d,
+                        peaks=device_peaks or peaks(dev.device_kind),
                         t_process=0.0, t0=srv.t0, t1=srv.t1,
                         requests=srv.recs, rounds=srv.rounds,
                         prefills=srv.prefills, compiles_window=0)

@@ -5,6 +5,7 @@ from pathlib import Path
 import pytest
 
 from bench.dims import dims_of, load_config
+from bench.families import family_of
 from bench.weights import n_params
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -42,7 +43,9 @@ def test_published_widths(name):
 
 @pytest.mark.parametrize("name", sorted(PUBLISHED))
 def test_parameter_count(name):
-    assert n_params(dims_of(load_config(name))) == PARAMS[name]
+    spec = load_config(name)
+    fam = family_of(spec)
+    assert n_params(fam, fam.dims_of(spec)) == PARAMS[name]
 
 
 def test_benchmark_names_the_files():

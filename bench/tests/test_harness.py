@@ -65,13 +65,16 @@ def test_altered_token_is_caught(monkeypatch):
 
 
 def test_decode_that_leaves_the_pool_unchanged_is_caught(monkeypatch):
-    """A decode step that returns its state (the K/V pool) unchanged."""
+    """A decode step that returns its state (the K/V pool) unchanged.
+    The round donates the pool, so the state put back is a copy taken
+    before it (the pool itself is deleted by then)."""
+    import jax.numpy as jnp
     from repro.serving import engine
 
     real = engine.Engine.paged_step_logits
 
     def stale(self, slot_tokens):
-        k, v = self.pool.k_pool, self.pool.v_pool
+        k, v = jnp.copy(self.pool.k_pool), jnp.copy(self.pool.v_pool)
         logits = real(self, slot_tokens)
         self.pool.k_pool, self.pool.v_pool = k, v
         return logits
